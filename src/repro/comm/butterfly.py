@@ -60,8 +60,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.quant import wire as wf
 from repro.comm.hierarchy import (_INTRA_SALT, _TREE_DOWN_SALT, _hier_shape,
                                   _mesh_axes, tree_rounds)
-from repro.comm.reduce_base import PackCounter, hop_key, seg_len, segment
-from repro.parallel.axes import shard_map_compat
+from repro.comm.reduce_base import (PackCounter, hop_key, pack_hop, seg_len,
+                                    segment)
+from repro.parallel.axes import auto_axes
 
 _FOLD_SALT = 0xF01D  # non-power-of-two pre-fold packs
 _HALVE_SALT = 0xBF1F  # recursive-halving reduce-scatter packs
@@ -183,9 +184,9 @@ def butterfly_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
         for g in range(G):
             for p in range(Pn):
                 c = (p - step) % Pn
-                pk = wf.pack_nsd(acc[g][p][c],
-                                 hop_key(key, _INTRA_SALT, step, g, p),
-                                 cfg.s, cfg.chunk)
+                pk = pack_hop(acc[g][p][c],
+                              hop_key(key, _INTRA_SALT, step, g, p),
+                              cfg.s, cfg.chunk)
                 ctr.count(pk, seg=c, link="ici")
                 packed.append((g, p, c, pk))
         for g, p, c, pk in packed:
@@ -205,8 +206,8 @@ def butterfly_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
     for g in range(G2, G):
         dst = g - G2
         for c in range(Pn):
-            pk = wf.pack_nsd(part[g][c], hop_key(key, _FOLD_SALT, 0, g, c),
-                             cfg.s, cfg.chunk)
+            pk = pack_hop(part[g][c], hop_key(key, _FOLD_SALT, 0, g, c),
+                          cfg.s, cfg.chunk)
             ctr.count(pk, seg=c, link="dcn")
             charge(pk, g, dst)
             part[dst][c] = part[dst][c] + wf.unpack_nsd(pk)
@@ -222,8 +223,8 @@ def butterfly_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
             dst = g ^ (1 << bit)
             for c in range(Pn):
                 block = live[g][c][(1 - keep) * half:(2 - keep) * half]
-                pk = wf.pack_nsd(block, hop_key(key, _HALVE_SALT, r, g, c),
-                                 cfg.s, cfg.chunk)
+                pk = pack_hop(block, hop_key(key, _HALVE_SALT, r, g, c),
+                              cfg.s, cfg.chunk)
                 ctr.count(pk, seg=c, link="dcn")
                 charge(pk, g, dst)
                 sends.append((dst, c, keep, pk))
@@ -238,9 +239,9 @@ def butterfly_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
 
     # --- phase 2b: pack the owned piece once; recursive doubling forwards
     # the piece packs verbatim until every pod holds the identical set ---
-    finals = [[wf.pack_nsd(live[g][c],
-                           hop_key(key, _TREE_DOWN_SALT, 0, g, c),
-                           cfg.s, cfg.chunk)
+    finals = [[pack_hop(live[g][c],
+                        hop_key(key, _TREE_DOWN_SALT, 0, g, c),
+                        cfg.s, cfg.chunk)
                for c in range(Pn)] for g in range(G2)]
     for g in range(G2):
         for c in range(Pn):
@@ -338,9 +339,9 @@ def make_butterfly_allreduce(mesh: Mesh,
         # --- phase 1: intra-pod ring reduce-scatter (hierarchy-identical) ---
         for step in range(Pn - 1):
             c_send = (me - step) % Pn
-            pk = wf.pack_nsd(jnp.take(acc, c_send, axis=0),
-                             hop_key(key, _INTRA_SALT, step, g, me),
-                             cfg.s, cfg.chunk)
+            pk = pack_hop(jnp.take(acc, c_send, axis=0),
+                          hop_key(key, _INTRA_SALT, step, g, me),
+                          cfg.s, cfg.chunk)
             ctr.count(pk, seg=c_send, link="ici")
             pk_in = perm_n(pk)
             c_recv = (me - 1 - step) % Pn
@@ -355,8 +356,8 @@ def make_butterfly_allreduce(mesh: Mesh,
         if G2 < G:
             is_extra = (g >= G2).astype(jnp.float32)
             is_rcvr = (g < G - G2).astype(jnp.float32)
-            pk = wf.pack_nsd(live, hop_key(key, _FOLD_SALT, 0, g, c_own),
-                             cfg.s, cfg.chunk)
+            pk = pack_hop(live, hop_key(key, _FOLD_SALT, 0, g, c_own),
+                          cfg.s, cfg.chunk)
             ctr.count(pk, seg=c_own, link="dcn", weight=is_extra)
             perm = [(src, src - G2) for src in range(G2, G)]
             pk_in = jax.lax.ppermute(pk, axis_name=pod_axis, perm=perm)
@@ -373,8 +374,8 @@ def make_butterfly_allreduce(mesh: Mesh,
             keep = (g >> bit) & 1
             block = jax.lax.dynamic_slice(live, ((1 - keep) * half,),
                                           (half,))
-            pk = wf.pack_nsd(block, hop_key(key, _HALVE_SALT, r, g, c_own),
-                             cfg.s, cfg.chunk)
+            pk = pack_hop(block, hop_key(key, _HALVE_SALT, r, g, c_own),
+                          cfg.s, cfg.chunk)
             ctr.count(pk, seg=c_own, link="dcn", weight=in_core)
             perm = [(a, a ^ (1 << bit)) for a in range(G2)]
             pk_in = jax.lax.ppermute(pk, axis_name=pod_axis, perm=perm)
@@ -385,8 +386,8 @@ def make_butterfly_allreduce(mesh: Mesh,
 
         # --- phase 2b: pack the owned piece once; recursive doubling of
         # the stacked (G2, ...) pack set, entries selected by round mask ---
-        pk_mine = wf.pack_nsd(live, hop_key(key, _TREE_DOWN_SALT, 0, g,
-                                            c_own), cfg.s, cfg.chunk)
+        pk_mine = pack_hop(live, hop_key(key, _TREE_DOWN_SALT, 0, g,
+                                         c_own), cfg.s, cfg.chunk)
         ctr.count(pk_mine, seg=c_own, link="dcn", hops=0, weight=in_core)
         slot = jnp.clip(g, 0, G2 - 1)
         packs = jax.tree.map(
@@ -457,8 +458,8 @@ def make_butterfly_allreduce(mesh: Mesh,
                 (jnp.max(bound) / n)[None], peak[None])
 
     spec = P((pod_axis, node_axis))
-    return jax.jit(shard_map_compat(
-        bfly, mesh=mesh,
+    return jax.jit(jax.shard_map(
+        bfly, mesh=auto_axes(mesh),
         in_specs=(spec, P()),
         out_specs=(spec, spec, spec, spec, spec)))
 

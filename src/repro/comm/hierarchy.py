@@ -57,8 +57,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.quant import wire as wf
-from repro.comm.reduce_base import PackCounter, hop_key, seg_len, segment
-from repro.parallel.axes import shard_map_compat
+from repro.comm.reduce_base import (PackCounter, hop_key, pack_hop, seg_len,
+                                    segment)
+from repro.parallel.axes import auto_axes
 
 _INTRA_SALT = 0x1C1A  # intra-pod ring reduce-scatter packs
 _TREE_UP_SALT = 0x7EE0  # inter-pod tree-reduce packs
@@ -174,9 +175,9 @@ def hier_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
         for g in range(G):
             for p in range(Pn):
                 c = (p - step) % Pn
-                pk = wf.pack_nsd(acc[g][p][c],
-                                 hop_key(key, _INTRA_SALT, step, g, p),
-                                 cfg.s, cfg.chunk)
+                pk = pack_hop(acc[g][p][c],
+                              hop_key(key, _INTRA_SALT, step, g, p),
+                              cfg.s, cfg.chunk)
                 ctr.count(pk, seg=c, link="ici")
                 packed.append((g, p, c, pk))
         for g, p, c, pk in packed:
@@ -200,9 +201,9 @@ def hier_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
                 continue
             dst = g - stride
             for c in range(Pn):
-                pk = wf.pack_nsd(part[g][c],
-                                 hop_key(key, _TREE_UP_SALT, r, g, c),
-                                 cfg.s, cfg.chunk)
+                pk = pack_hop(part[g][c],
+                              hop_key(key, _TREE_UP_SALT, r, g, c),
+                              cfg.s, cfg.chunk)
                 ctr.count(pk, seg=c, link="dcn")
                 b = pk.wire_bytes().astype(jnp.float32)
                 traffic[g] = traffic[g] + b
@@ -213,8 +214,8 @@ def hier_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
     # (G-1 DCN hops) then around each pod's ring (P-1 ICI hops per pod) ---
     finals = []
     for c in range(Pn):
-        pk = wf.pack_nsd(part[0][c], hop_key(key, _TREE_DOWN_SALT, 0, 0, c),
-                         cfg.s, cfg.chunk)
+        pk = pack_hop(part[0][c], hop_key(key, _TREE_DOWN_SALT, 0, 0, c),
+                      cfg.s, cfg.chunk)
         ctr.count(pk, seg=c, link="dcn", hops=G - 1)
         ctr.count(pk, link="ici", hops=G * (Pn - 1))
         b = pk.wire_bytes().astype(jnp.float32)
@@ -289,9 +290,9 @@ def _make_hier_allreduce(mesh: Mesh, cfg: HierConfig = HierConfig(),
         # --- phase 1: intra-pod ring reduce-scatter over the node axis ---
         for step in range(Pn - 1):
             c_send = (me - step) % Pn
-            pk = wf.pack_nsd(jnp.take(acc, c_send, axis=0),
-                             hop_key(key, _INTRA_SALT, step, g, me),
-                             cfg.s, cfg.chunk)
+            pk = pack_hop(jnp.take(acc, c_send, axis=0),
+                          hop_key(key, _INTRA_SALT, step, g, me),
+                          cfg.s, cfg.chunk)
             ctr.count(pk, seg=c_send, link="ici")
             pk_in = perm_n(pk)
             c_recv = (me - 1 - step) % Pn
@@ -307,8 +308,8 @@ def _make_hier_allreduce(mesh: Mesh, cfg: HierConfig = HierConfig(),
         for r in range(rounds):
             stride = 1 << r
             is_sender = (g % (2 * stride)) == stride
-            pk = wf.pack_nsd(part, hop_key(key, _TREE_UP_SALT, r, g, c_own),
-                             cfg.s, cfg.chunk)
+            pk = pack_hop(part, hop_key(key, _TREE_UP_SALT, r, g, c_own),
+                          cfg.s, cfg.chunk)
             ctr.count(pk, seg=c_own, link="dcn", weight=is_sender)
             perm = [(src, src - stride) for src in range(G)
                     if src % (2 * stride) == stride]
@@ -317,8 +318,8 @@ def _make_hier_allreduce(mesh: Mesh, cfg: HierConfig = HierConfig(),
 
         # --- phase 3: pod 0's owner packs the global segment once, then
         # the pack travels down the tree verbatim (receivers ADOPT it) ---
-        pk = wf.pack_nsd(part, hop_key(key, _TREE_DOWN_SALT, 0, 0, c_own),
-                         cfg.s, cfg.chunk)
+        pk = pack_hop(part, hop_key(key, _TREE_DOWN_SALT, 0, 0, c_own),
+                      cfg.s, cfg.chunk)
         is_root = (g == 0)
         ctr.count(pk, seg=c_own, link="dcn", hops=0, weight=is_root)
         for r in range(rounds - 1, -1, -1):
@@ -353,8 +354,8 @@ def _make_hier_allreduce(mesh: Mesh, cfg: HierConfig = HierConfig(),
                 (jnp.max(bound) / n)[None])
 
     spec = P((pod_axis, node_axis))
-    return jax.jit(shard_map_compat(
-        hier, mesh=mesh,
+    return jax.jit(jax.shard_map(
+        hier, mesh=auto_axes(mesh),
         in_specs=(spec, P()),
         out_specs=(spec, spec, spec, spec)))
 

@@ -26,6 +26,9 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import nsd
+from repro.quant import wire as wf
+
 
 class ReduceTelemetry(NamedTuple):
     """Per-reduce accounting shared by the flat ring and the hierarchy.
@@ -60,6 +63,23 @@ def segment(flat: jax.Array, n: int, chunk: int) -> Tuple[jax.Array, int]:
     seg = seg_len(size, n, chunk)
     padded = jnp.pad(flat, (0, n * seg - size))
     return padded.reshape(n, seg), seg
+
+
+def pack_hop(x: jax.Array, key: jax.Array, s: float, chunk: int
+             ) -> wf.PackedNSD:
+    """NSD-pack ``x`` for one hop, with a Delta at which no level clips.
+
+    Delta is ``wf.pack_nsd``'s s * std, raised to max|x| / INT8_CLIP where
+    that is larger. A heavy-tailed segment, such as an embedding gradient
+    whose few touched rows hold all its mass, would otherwise clip its
+    largest entries at +-127 Delta: the error there is unbounded by Delta
+    and biased, and the error bound the reduce sums would not hold.
+    """
+    delta = jnp.maximum(nsd.compute_delta(x, s),
+                        jnp.max(jnp.abs(x.astype(jnp.float32)))
+                        / nsd.INT8_CLIP)
+    k = nsd.nsd_indices(x, key, delta)
+    return wf.pack_indices(k, delta, x.shape, x.dtype, chunk)
 
 
 def hop_key(key: jax.Array, salt: int, *indices) -> jax.Array:

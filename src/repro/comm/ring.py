@@ -47,8 +47,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.quant import wire as wf
 from repro.comm.reduce_base import (PackCounter, ReduceTelemetry, hop_key,
-                                    seg_len, segment)
-from repro.parallel.axes import shard_map_compat
+                                    pack_hop, seg_len, segment)
+from repro.parallel.axes import auto_axes
 
 _REDUCE_SALT = 0x51D5
 _GATHER_SALT = 0xA11C
@@ -100,8 +100,8 @@ def ring_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
         packed = []
         for i in range(n):
             c = (i - step) % n
-            p = wf.pack_nsd(acc[i][c], hop_key(key, _REDUCE_SALT, step, i),
-                            cfg.s, cfg.chunk)
+            p = pack_hop(acc[i][c], hop_key(key, _REDUCE_SALT, step, i),
+                         cfg.s, cfg.chunk)
             packed.append((c, p))
             ctr.count(p, seg=c)
         for i in range(n):
@@ -113,8 +113,8 @@ def ring_allreduce_nsd(grads: Union[jax.Array, Sequence[jax.Array]],
     gathered = []
     for c in range(n):
         owner = (c - 1) % n
-        p = wf.pack_nsd(acc[owner][c], hop_key(key, _GATHER_SALT, c, 0),
-                        cfg.s, cfg.chunk)
+        p = pack_hop(acc[owner][c], hop_key(key, _GATHER_SALT, c, 0),
+                     cfg.s, cfg.chunk)
         ctr.count(p, seg=c, hops=n - 1)
         gathered.append(wf.unpack_nsd(p))
 
@@ -154,9 +154,9 @@ def make_ring_allreduce(mesh: Mesh, axis_name: str,
 
         for step in range(n - 1):
             c_send = (me - step) % n
-            p = wf.pack_nsd(jnp.take(acc, c_send, axis=0),
-                            hop_key(key, _REDUCE_SALT, step, me),
-                            cfg.s, cfg.chunk)
+            p = pack_hop(jnp.take(acc, c_send, axis=0),
+                         hop_key(key, _REDUCE_SALT, step, me),
+                         cfg.s, cfg.chunk)
             ctr.count(p, seg=c_send)
             p_in = perm(p)
             c_recv = (me - 1 - step) % n
@@ -164,9 +164,9 @@ def make_ring_allreduce(mesh: Mesh, axis_name: str,
                 jnp.take(acc, c_recv, axis=0) + wf.unpack_nsd(p_in))
 
         c_own = (me + 1) % n  # node m finished segment m+1
-        p = wf.pack_nsd(jnp.take(acc, c_own, axis=0),
-                        hop_key(key, _GATHER_SALT, c_own, 0),
-                        cfg.s, cfg.chunk)
+        p = pack_hop(jnp.take(acc, c_own, axis=0),
+                     hop_key(key, _GATHER_SALT, c_own, 0),
+                     cfg.s, cfg.chunk)
         ctr.count(p, seg=c_own, hops=0)  # charge the Delta; bytes per hop
         out = jnp.zeros_like(acc).at[c_own].set(wf.unpack_nsd(p))
         cur = p
@@ -184,8 +184,8 @@ def make_ring_allreduce(mesh: Mesh, axis_name: str,
         mean = (out.reshape(-1)[:size] / n).reshape(shape).astype(dtype)
         return mean[None], ctr.wire_total[None], (jnp.max(bound) / n)[None]
 
-    return jax.jit(shard_map_compat(
-        ring, mesh=mesh,
+    return jax.jit(jax.shard_map(
+        ring, mesh=auto_axes(mesh),
         in_specs=(P(axis_name), P()),
         out_specs=(P(axis_name), P(axis_name), P(axis_name))))
 

@@ -23,7 +23,13 @@ ARCH_IDS = tuple(_MODULES)
 
 
 def get_model(arch_id: str):
-    """Full-size config (dry-run only: never materialize these params)."""
+    """Full-size (published) config.
+
+    Configs whose params, optimizer state and activations fit one chip run
+    on it (mamba2-370m trains on one 16 GB v5e; see ``chip_smoke.py``);
+    the larger ones are materialized only on a sharded deployment and are
+    otherwise compiled by the dry-run from shapes alone.
+    """
     return importlib.import_module(_MODULES[arch_id]).config()
 
 

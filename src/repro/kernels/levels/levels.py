@@ -35,8 +35,8 @@ in round 0); descending bit order keeps intermediate targets distinct.
 
 Both kernels are bit-exact vs ``repro.quant.wire._compact``/``_expand``
 composition in interpret mode for every shape, including all-zero and
-all-nonzero chunks (tests/test_levels_kernel.py); compiled mode stays
-``xfail(strict=False)`` pending a real-TPU host like the other kernels.
+all-nonzero chunks (tests/test_levels_kernel.py); tests/test_tpu_compile.py
+compiles them for a described TPU chip.
 """
 from __future__ import annotations
 

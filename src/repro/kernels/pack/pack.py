@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.backend import default_interpret
+from repro.kernels.backend import default_interpret, write_tile_count
 
 
 def _pack_kernel(kt_ref, bitmap_ref, nnz_ref):
@@ -61,7 +61,7 @@ def _pack_kernel(kt_ref, bitmap_ref, nnz_ref):
     acc = acc | pltpu.roll(acc, bn - 2, 0)
     acc = acc | pltpu.roll(acc, bn - 4, 0)
     bitmap_ref[...] = acc.reshape(bn // 8, 8, bm)[:, 0, :].astype(jnp.uint8)
-    nnz_ref[0, 0] = jnp.sum(bits)
+    write_tile_count(nnz_ref, pl.program_id(1), jnp.sum(bits))
 
 
 def _unpack_kernel(bitmap_ref, mask_ref):
@@ -85,21 +85,23 @@ def bitmap_pack_blocked(k: jax.Array, *, bm: int = 128, bn: int = 128,
     M, N = k.shape
     assert M % bm == 0 and N % bn == 0 and bn % 8 == 0, (k.shape, bm, bn)
     grid = (N // bn, M // bm)
-    bitmap_t, nnz = pl.pallas_call(
+    # nnz is gathered per column of tiles (the inner grid axis walks rows)
+    # into a resident (1, M // bm) count row; see nsd_quant for why
+    bitmap_t, nnz_cols = pl.pallas_call(
         _pack_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bn, bm), lambda j, i: (j, i))],
         out_specs=[
             pl.BlockSpec((bn // 8, bm), lambda j, i: (j, i)),
-            pl.BlockSpec((1, 1), lambda j, i: (i, j)),
+            pl.BlockSpec((None, 1, M // bm), lambda j, i: (j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((N // 8, M), jnp.uint8),
-            jax.ShapeDtypeStruct((M // bm, N // bn), jnp.int32),
+            jax.ShapeDtypeStruct((N // bn, 1, M // bm), jnp.int32),
         ],
         interpret=interpret,
     )(k.T)
-    return bitmap_t.T, nnz
+    return bitmap_t.T, nnz_cols[:, 0, :].T
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))

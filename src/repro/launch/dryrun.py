@@ -244,12 +244,7 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool = False,
                                       memory=memory)
             compiled = lowered.compile()
         compile_s = time.time() - t0
-        # cost_analysis() returns a bare dict on newer jax, a one-element
-        # list of dicts on the 0.4.x line CI pins
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        cost = dict(ca)
+        cost = dict(compiled.cost_analysis())
         mem = compiled.memory_analysis()
         mem_stats = {
             "argument_bytes": getattr(mem, "argument_size_in_bytes", 0),

@@ -13,19 +13,17 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.parallel.axes import axis_link_kind
 
 
 def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions: ``axis_types`` (and the Auto axis
-    type itself) only exist in newer releases; older ones default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis Auto: the compiler propagates
+    shardings, and code may index a sharded array without naming the
+    output sharding (``jax.make_mesh``'s own default is Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

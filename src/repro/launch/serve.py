@@ -25,6 +25,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_model, get_smoke_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.monitor import MonitorSuite, ServeMonitor
 from repro.obs.runlog import run_obs
 from repro.serve import ServeConfig, Supervisor
@@ -104,6 +105,7 @@ def main() -> None:
                     help="0: auto from request sizes")
     ap.add_argument("--run-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if (args.serve is None) == (args.arch is None):
         raise SystemExit("exactly one of --serve / --arch is required")

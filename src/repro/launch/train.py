@@ -5,8 +5,10 @@
 
 Presets:
     smoke  — the arch's reduced config, tiny batch (CPU-runnable)
-    full   — the assigned full config (needs a real cluster; on CPU this is
-             only useful with --dry-run-first to validate the mesh)
+    full   — the arch's published config. Configs whose weights, AdamW
+             state and activations fit one chip run on it (mamba2-370m on
+             one v5e: ``python chip_smoke.py`` drives exactly this path);
+             larger ones need a sharded deployment.
 
 On a multi-host cluster, call jax.distributed.initialize() via
 --distributed (standard TPU pod env) before anything touches devices.
@@ -14,12 +16,14 @@ On a multi-host cluster, call jax.distributed.initialize() via
 from __future__ import annotations
 
 import argparse
+from typing import List, Optional
 
 import jax
 
 from repro.configs import ARCH_IDS, get_model, get_smoke_model
 from repro.core.policy import DitherPolicy
 from repro.data import TokenStreamConfig, token_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.program import format_program, merge_legacy_flags
 from repro.optim import OptConfig
 from repro.train import Trainer, TrainerConfig
@@ -52,7 +56,7 @@ def batch_fn_for(model, batch: int, seq: int):
     return fn
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--preset", choices=["smoke", "full"], default="smoke")
@@ -94,11 +98,12 @@ def main() -> None:
                     help="with --run-dir: critical health events (NaN "
                     "loss, sparsity collapse) raise instead of warn")
     ap.add_argument("--distributed", action="store_true")
-    args = ap.parse_args()
+    return ap
 
-    if args.distributed:
-        jax.distributed.initialize()
 
+def run(args: argparse.Namespace) -> dict:
+    """Train as ``args`` says; returns ``Trainer.fit``'s output plus the
+    ``trainer`` itself (its step can be lowered again for inspection)."""
     model = (get_smoke_model if args.preset == "smoke" else get_model)(
         args.arch)
     spec = merge_legacy_flags(args.program, args.policy_program,
@@ -172,6 +177,15 @@ def main() -> None:
     if args.run_dir:
         log.info("run dir: %s (render: python -m repro.obs.report %s)",
                  args.run_dir, args.run_dir)
+    return dict(out, trainer=trainer)
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
+    if args.distributed:
+        jax.distributed.initialize()
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

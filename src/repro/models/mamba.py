@@ -121,11 +121,15 @@ def _ssd_chunked(x, dt, A, Bm, Cm, cfg: SSMConfig,
 
     # ---- intra-chunk (quadratic in Q) ----
     # L[i,j] = exp(cum_i - cum_j) for i >= j (exp/cumsum stay f32; only the
-    # matmul OPERANDS drop to intra_dtype, accumulating in f32)
+    # matmul OPERANDS drop to intra_dtype, accumulating in f32). The mask
+    # goes INSIDE the exp: above the diagonal cum_i - cum_j > 0 grows with
+    # the chunk (past 88 at Q=256) and exp overflows to inf, whose
+    # gradient through a where-after-exp is 0 * inf = NaN.
     op_dtype = jnp.bfloat16 if cfg.intra_dtype == "bf16" else jnp.float32
     diff = cum[:, :, :, None] - cum[:, :, None, :, :, :]  # (B,nc,Q,Q,G,rep)
     tri = jnp.tril(jnp.ones((Q, Q), bool))
-    Lmat = jnp.where(tri[None, None, :, :, None, None], jnp.exp(diff), 0.0)
+    Lmat = jnp.exp(jnp.where(tri[None, None, :, :, None, None], diff,
+                             -jnp.inf))
     # scores are per-GROUP (shared by rep heads): 1/rep of the naive FLOPs
     scores = jnp.einsum("bcign,bcjgn->bcijg", Cg.astype(op_dtype),
                         Bg.astype(op_dtype),

@@ -271,13 +271,13 @@ def moe_a2a(params: Params, x2d: jax.Array, cfg: MoEConfig,
         aux = jax.lax.pmean(aux, data_axes + (ep_axis,))
         return out, aux
 
-    out, aux = axlib.shard_map_compat(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(token_axes, None), P(None, None), P(ep_axis, None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None), P(), P(),
                   P()),
         out_specs=(P(token_axes, None), P()),
-        check=False,
+        check_vma=False,
     )(x2d, params["router"], params["w_gate"], params["w_up"],
       params["w_down"], key, step, ctrl_vec)
 

@@ -18,7 +18,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -34,19 +34,19 @@ def axis_link_kind(axis_name: str) -> str:
     return LINK_KINDS.get(axis_name, "ici")
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across jax versions: the entry point moved from
-    ``jax.experimental.shard_map`` to ``jax.shard_map`` and the replication
-    check was renamed ``check_rep`` -> ``check_vma`` (at different releases,
-    so all four combinations exist in the wild)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    import inspect
-    params = inspect.signature(sm).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{check_kw: check})
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis Auto.
+
+    On an Explicit axis (``jax.make_mesh``'s default) a sharded output
+    carries its sharding in its type, and plain indexing such as
+    ``means[0]`` must then name an output sharding. Code that builds
+    collectives over a caller's mesh normalizes it here, so its outputs
+    index like any array whichever mesh the caller made.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 @dataclasses.dataclass

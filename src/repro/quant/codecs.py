@@ -317,12 +317,18 @@ class NsdCodec(Codec):
 
     def error_bound(self, spec, enc):
         # NSD error is < Delta per element (|x + nu - Delta k| <= Delta/2,
-        # |nu| <= Delta/2). Valid for non-saturated elements (|k| < 127) —
-        # the clip is a safety net, not part of the bound.
+        # |nu| <= Delta/2). Nothing in the encoding bounds it where a level
+        # sits at the clip (|k| = INT8_CLIP may have been cut off) or where
+        # Delta is 0 (std 0, as for one element: every level is 0 whatever
+        # x held); the bound there is inf.
         n = _nelems(enc.shape)
         per_elem = jnp.broadcast_to(
             enc.deltas[:, None], (enc.n_chunks, enc.chunk)).reshape(-1)
-        return per_elem[:n].reshape(enc.shape)
+        k = wire._expand(enc.levels,
+                         wire.unpack_bitmap(enc.bitmap).reshape(-1))
+        unbounded = ((per_elem <= 0.0)
+                     | (jnp.abs(k.astype(jnp.int32)) >= nsd.INT8_CLIP))
+        return jnp.where(unbounded, jnp.inf, per_elem)[:n].reshape(enc.shape)
 
     def compute_on_packed(self, spec, enc, x, w, *, backend: str = "jnp"):
         """Both backward products of y = x @ w from the packed cotangent.

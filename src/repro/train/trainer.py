@@ -87,7 +87,12 @@ class Trainer:
         # phase_policy is static: a PolicyProgram phase boundary retraces
         # exactly once; knob schedules / controller nudges are traced and
         # re-use the compiled step (tests/test_schedule.py pins this).
-        self._jit_step = jax.jit(self._step, static_argnames=("phase_policy",))
+        # params and optimizer state are donated: the step's outputs take
+        # over their buffers, so a step holds one copy of the model state
+        # (the old ones are never read again; checkpoints copy to host
+        # before the next step runs)
+        self._jit_step = jax.jit(self._step, static_argnames=("phase_policy",),
+                                 donate_argnames=("params", "opt_state"))
         self.history: list = []
 
     # one optimizer step with optional micro-batch gradient accumulation
@@ -208,6 +213,23 @@ class Trainer:
                      int(opt_state["step"]))
         return params, opt_state, specs
 
+    def _phase_policy(self, step: int):
+        return (self.program.phase_policy_at(step)
+                if self.program is not None else None)
+
+    def lower_step(self, params, opt_state, batch, *, step: int = 0):
+        """Lower the jitted step for these inputs without running it.
+
+        Inputs may be arrays or ``jax.ShapeDtypeStruct``s. ``.compile()``
+        on the result gives the executable's HLO text and memory analysis.
+        """
+        base_key = jax.eval_shape(
+            lambda: jax.random.fold_in(jax.random.PRNGKey(0), 0xD17E))
+        comm_state = jax.eval_shape(self._init_comm_state, params)
+        return self._jit_step.lower(
+            params, opt_state, batch, base_key, comm_state, self._ctrl.state,
+            phase_policy=self._phase_policy(step))
+
     def fit(self, batch_iter: Iterator, params=None, opt_state=None
             ) -> Dict[str, Any]:
         key = jax.random.PRNGKey(self.tcfg.seed)
@@ -243,8 +265,7 @@ class Trainer:
                 if isinstance(batch, tuple):  # (step, batch) loaders
                     batch = batch[1]
             self._init_ctrl_state(params, batch)
-            phase_policy = (self.program.phase_policy_at(step)
-                            if self.program is not None else None)
+            phase_policy = self._phase_policy(step)
             with sp("dispatch"):
                 params, opt_state, metrics, comm_state = self._jit_step(
                     params, opt_state, batch, base_key, comm_state,
@@ -263,7 +284,10 @@ class Trainer:
                     step + 1, {k: float(v) for k, v in metrics.items()})
             if self.tcfg.log_every and (step + 1) % self.tcfg.log_every == 0:
                 loss = float(metrics["loss"])
-                row = {"step": step + 1, "loss": loss}
+                # time_s: wall seconds since the loop started, read once
+                # this step's loss has reached the host
+                row = {"step": step + 1, "loss": loss,
+                       "time_s": time.time() - t0}
                 if "comm_wire_bytes" in metrics:
                     wire = float(metrics["comm_wire_bytes"])
                     row["comm_wire_mb"] = wire / 1e6
@@ -273,7 +297,7 @@ class Trainer:
                             wire, pods=self.topology.pods))
                 self.history.append(row)
                 log.info("step %d loss %.4f (%.2f s)", step + 1, loss,
-                         time.time() - t0)
+                         row["time_s"])
             if (self.ckpt is not None and self.tcfg.ckpt_every
                     and (step + 1) % self.tcfg.ckpt_every == 0):
                 with sp("checkpoint"):

@@ -14,6 +14,7 @@ import stat_utils
 from repro.comm import (CommPolicy, RingConfig, compress_tree,
                         init_comm_state, pack_nsd, ring_allreduce_nsd,
                         topk_error_feedback, unpack_nsd, wireformat)
+from repro.comm.reduce_base import pack_hop
 from repro.core import nsd
 from repro.obs import metrics as statslib
 from repro.kernels.pack.pack import (bitmap_pack_blocked,
@@ -95,19 +96,9 @@ class TestWireFormat:
                                       np.asarray(want))
 
 
-# Compiled-mode guard: interpret=True must pass everywhere. The kernels
-# now use the Mosaic-lowerable sublane-rotate + OR-reduce layout (no
-# lane-dim reshape — tests/test_pack_layout.py pins that structurally),
-# but CPU still has no compiled pallas_call at all, so the compiled
-# variant stays xfail(strict=False): a visible xfail on CPU CI and a
-# plain pass on a real TPU host.
-INTERPRET_MODES = [
-    True,
-    pytest.param(False, marks=pytest.mark.xfail(
-        strict=False,
-        reason="CPU has no compiled pallas; on TPU the sublane-rotate "
-               "layout is expected to compile and pass")),
-]
+# interpret mode only: CPU has no compiled pallas_call.
+# tests/test_tpu_compile.py compiles these kernels for a described chip
+INTERPRET_MODES = [True]
 
 
 class TestPackKernels:
@@ -162,6 +153,34 @@ class TestRing:
         dense = jnp.mean(gs, axis=0)
         err = float(jnp.max(jnp.abs(mean - dense)))
         stat_utils.assert_within_bound(err, tele.error_bound)
+
+    def test_heavy_tailed_leaf_within_bound(self, key):
+        """An embedding-like gradient (a few touched rows hold all the
+        mass, max/std in the hundreds) would clip at +-127 Delta on the
+        wire; hops raise Delta instead, so the bound still holds."""
+        n, rows, width, hot = 4, 32768, 32, 1
+        gs = []
+        for i in range(n):
+            k_rows, k_vals = jax.random.split(jax.random.fold_in(key, i))
+            touched = jax.random.choice(k_rows, rows, (hot,), replace=False)
+            g = jnp.zeros((rows, width)).at[touched].set(
+                jax.random.normal(k_vals, (hot, width)))
+            gs.append(g)
+        gs = jnp.stack(gs)
+        # within the ring segment that holds node 0's row, max/std > 127
+        seg = gs[0].reshape(n, -1)
+        ratio = jnp.max(jnp.abs(seg), 1) / jnp.maximum(jnp.std(seg, 1), 1e-30)
+        assert float(jnp.max(ratio)) > 127
+        mean, tele = ring_allreduce_nsd(gs, key, RingConfig(s=1.0))
+        err = float(jnp.max(jnp.abs(mean - jnp.mean(gs, axis=0))))
+        assert err <= float(tele.error_bound), (err, float(tele.error_bound))
+
+    def test_hop_pack_never_clips(self, key):
+        x = jnp.zeros((65536,)).at[7].set(1000.0).at[9].set(-3.0)
+        p = pack_hop(x, key, 1.0, 256)
+        assert int(jnp.max(jnp.abs(p.levels))) <= nsd.INT8_CLIP
+        err = jnp.abs(unpack_nsd(p) - x)
+        assert float(jnp.max(err)) <= float(p.deltas[0])
 
     def test_ring_wire_under_25pct_at_paper_sparsity(self, key):
         """At the ~92% sparsity operating point the whole exchange must be
@@ -372,7 +391,8 @@ SHARDMAP_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from repro.comm import (RingConfig, make_ring_allreduce,
                             ring_allreduce_nsd)
-    mesh = jax.make_mesh((8,), ("nodes",))
+    from repro.launch import make_mesh
+    mesh = make_mesh((8,), ("nodes",))
     key = jax.random.PRNGKey(0)
     gs = jnp.stack([jax.random.normal(jax.random.fold_in(key, i), (37, 13))
                     for i in range(8)])
@@ -392,6 +412,11 @@ SHARDMAP_SCRIPT = textwrap.dedent("""
     assert float(jnp.sum(wires)) == float(tele.wire_bytes)
     # per-hop delta accounting must agree with the sim's error bound too
     assert abs(float(bounds[0]) - float(tele.error_bound)) < 1e-6
+    # a caller's mesh with Explicit axes (jax.make_mesh's default) gives
+    # the same result, and its output indexes like any array
+    means_x, _, _ = make_ring_allreduce(
+        jax.make_mesh((8,), ("nodes",)), "nodes", RingConfig(s=1.0))(gs, key)
+    assert float(jnp.max(jnp.abs(means_x[0] - sim_mean))) == 0.0
     # dispatcher: telemetry populated and node-count mismatch rejected
     from repro.comm import allreduce_compressed
     mean_d, tele_d = allreduce_compressed(gs, key, RingConfig(s=1.0),
@@ -430,7 +455,8 @@ def test_ring_shardmap_inprocess(key):
     import functools
 
     from repro.comm import make_ring_allreduce
-    mesh = jax.make_mesh((8,), ("nodes",))
+    from repro.launch import make_mesh
+    mesh = make_mesh((8,), ("nodes",))
     gs = jnp.stack([jax.random.normal(jax.random.fold_in(key, i), (129,))
                     for i in range(8)])
     means, wires, bounds = make_ring_allreduce(

@@ -1,8 +1,7 @@
 """The Pallas levels compact/expand kernels (repro.kernels.levels): the
 chunk-local butterfly routing is BIT-EXACT against the cumsum oracle and
 against the wire format's global `_compact`/`_expand`, interpret mode on
-any host; compiled Mosaic is xfail(strict=False) off-TPU (same policy as
-tests/test_kernels.py)."""
+any host; tests/test_tpu_compile.py compiles them for a described chip."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,11 +14,8 @@ from repro.quant import wire
 
 CHUNK = 256
 
-INTERPRET_MODES = [
-    pytest.param(True, id="interpret"),
-    pytest.param(False, id="compiled", marks=pytest.mark.xfail(
-        strict=False, reason="compiled Mosaic needs a TPU host")),
-]
+# compiled for a described chip in tests/test_tpu_compile.py
+INTERPRET_MODES = [pytest.param(True, id="interpret")]
 
 
 @pytest.fixture(params=INTERPRET_MODES)

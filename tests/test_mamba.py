@@ -103,3 +103,26 @@ def test_chunked_ssd_non_divisible_seq(key):
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(h_c), np.asarray(h_n), rtol=1e-4,
                                atol=1e-4)
+
+
+def test_chunked_ssd_grads_finite_at_long_chunk(key):
+    """Within a 64-step chunk the decay sum dt * A passes 88, where the
+    masked upper triangle's exp(cum_i - cum_j) overflows f32. Gradients
+    must stay finite (the mamba2-370m config uses 256-step chunks)."""
+    Bsz, S, H, Pd, G, N = 1, 64, 4, 8, 1, 6
+    cfg = M.SSMConfig(d_model=32, d_inner=H * Pd, head_dim=Pd, d_state=N,
+                      n_groups=G, chunk=64)
+    ks = jax.random.split(key, 4)
+    x = jax.random.normal(ks[0], (Bsz, S, H, Pd))
+    dt = jnp.full((Bsz, S, H), 0.1)
+    A = -jnp.full((H,), 16.0)  # sum over the chunk: -102
+    Bm = jax.random.normal(ks[2], (Bsz, S, G, N)) * 0.5
+    Cm = jax.random.normal(ks[3], (Bsz, S, G, N)) * 0.5
+    grads = jax.grad(lambda *a: jnp.sum(M._ssd_chunked(*a, cfg)[0] ** 2),
+                     argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
+    for g in grads:
+        assert bool(jnp.all(jnp.isfinite(g)))
+    y_chunk, _ = M._ssd_chunked(x, dt, A, Bm, Cm, cfg)
+    y_naive, _ = _naive_ssd(x, dt, A, Bm, Cm)
+    np.testing.assert_allclose(np.asarray(y_chunk), np.asarray(y_naive),
+                               rtol=1e-4, atol=1e-4)

@@ -8,8 +8,8 @@ invariant to protect is: *no reshape inside either kernel body changes
 the trailing dimension*. This test walks the traced kernel jaxprs and
 asserts that, turning the "does it compile on TPU" question into a
 CPU-checkable structural property. Bit-exactness vs the wire format is
-covered by tests/test_comm.py::TestPackKernels; a real-TPU run of the
-compiled path stays the xfail red/green signal there.
+covered by tests/test_comm.py::TestPackKernels; tests/test_tpu_compile.py
+compiles the kernels for a described TPU chip.
 """
 import jax
 import jax.numpy as jnp

@@ -103,6 +103,28 @@ class TestErrorBounds:
         bound = error_bound(mode, enc)
         assert float(jnp.max(err / (bound + 1e-12))) <= 1.0 + 1e-4
 
+    def test_nsd_bound_is_delta_where_it_holds(self, key):
+        x = jax.random.normal(key, (24, 96)) * 5.0
+        enc = encode("nsd@2", x, key)
+        bound = error_bound("nsd@2", enc)
+        np.testing.assert_array_equal(
+            np.asarray(bound),
+            np.full(x.shape, float(enc.deltas[0]), np.float32))
+        assert float(jnp.max(jnp.abs(decode("nsd@2", enc) - x) - bound)) <= 0
+
+    @pytest.mark.parametrize("x", [[1.6], [1.0, 1.001]],
+                             ids=["one_element_delta_0", "level_clipped"])
+    def test_nsd_bound_inf_where_nothing_bounds_it(self, key, x):
+        """One element has std 0, so Delta is 0 and it decodes to 0; two
+        near-equal values have max/std far over 127/s, so a level clips.
+        Neither error is bounded by Delta, and the bound says so."""
+        x = jnp.asarray(x, jnp.float32)
+        enc = encode("nsd@0.5", x, key)
+        err = jnp.abs(decode("nsd@0.5", enc) - x)
+        bound = error_bound("nsd@0.5", enc)
+        assert bool(jnp.any(err > enc.deltas[0]))
+        assert bool(jnp.all(err <= bound)), (err, bound)
+
     def test_u8_bound_in_squared_domain(self, key):
         v = jnp.square(jax.random.normal(key, (8, 64)) * 3.0)
         enc = encode("u8", v, key)
