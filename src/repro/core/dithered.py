@@ -34,6 +34,21 @@ recomputes the forward from the op inputs rather than decoding. The codec
 choice is static per layer; knob schedules still recompile nothing
 (compile-counter pins in tests/test_memory.py).
 
+Tally (``ctx.tally``): an optional zero f32 ``(4,)`` input per layer name
+that each op of that name takes beside ``x`` and ``w``. Its cotangent is the
+work of the kernel path, summed over the name's kernel-variant contractions
+that ran backward: ``[live 128 x 128 tiles, tiles of the padded grid, zero
+levels, elements]`` (see :data:`TALLY_FIELDS`). ``Trainer._step``
+differentiates with respect to it beside the parameters, so the counts reach
+the step's metrics with no host callback; the other variants give it no
+cotangent. One input per name keeps each count's sum inside its own layer's
+backward: with one shared input, a scanned layer's out-projection count
+waited for its in-projection's, which moved XLA's memory-space assignment of
+the whole backward. Every backward rule runs
+under the ``dither/bwd`` named scope (sub-scopes ``noise``, ``nsd``,
+``pack``, ``matmul``, ``tally``), so a device profile can attribute its
+time.
+
 Variants (spec.variant):
   off     plain backprop
   paper   NSD in f32, products in the layer dtype      [faithful baseline]
@@ -56,6 +71,7 @@ from repro.core import meprop as meproplib
 from repro.core import nsd
 from repro.core import rowdither
 from repro.obs import metrics as statslib
+from repro.obs.trace import annotate
 from repro.core.policy import (
     KNOB_MEPROP_K_FRAC,
     KNOB_ROW_ALPHA,
@@ -140,7 +156,12 @@ def _re_bwd(tag, nbytes, res, g):
 _remat_emit.defvjp(_re_fwd, _re_bwd)
 
 
-def _apply_op(op: Callable, x, w, r, name: str):
+def _tally_for(ctx, name: str):
+    """The layer's tally input, or None where nothing is counted."""
+    return None if ctx.tally is None else ctx.tally.get(name)
+
+
+def _apply_op(op: Callable, x, w, r, name: str, tally=None):
     """Invoke a dithered op under the layer's resolved residual mode.
 
     Mode "remat" recomputes the op's forward in the VJP instead of
@@ -154,12 +175,12 @@ def _apply_op(op: Callable, x, w, r, name: str):
     """
     spec = r.spec
     if spec.residual != "remat":
-        return op(x, w, r.key, r.knobs, spec, name)
+        return op(x, w, r.key, r.knobs, tally, spec, name)
     collect = spec.collect_stats
     if collect:
         spec = dataclasses.replace(spec, collect_stats=False)
-    y = jax.checkpoint(op, static_argnums=(4, 5))(
-        x, w, r.key, r.knobs, spec, name)
+    y = jax.checkpoint(op, static_argnums=(5, 6))(
+        x, w, r.key, r.knobs, tally, spec, name)
     if collect:
         y = _remat_emit(y, r.spec.stats_tag + name,
                         _residlib().dense_nbytes(x.shape, x.dtype))
@@ -196,11 +217,13 @@ def quantize_cotangent(
             )
         return out
     if spec.variant in (VARIANT_PAPER, VARIANT_INT8, VARIANT_KERNEL):
-        delta = nsd.compute_delta(g, knobs[KNOB_S])
-        k = nsd.nsd_indices(g, key, delta)
-        if spec.collect_stats:
-            statslib.emit(spec.stats_tag + name, nsd.quant_stats(k, delta))
-        return (k.astype(jnp.float32) * delta).astype(g.dtype)
+        with annotate("nsd"):
+            delta = nsd.compute_delta(g, knobs[KNOB_S])
+            k = nsd.nsd_indices(g, key, delta)
+            if spec.collect_stats:
+                statslib.emit(spec.stats_tag + name,
+                              nsd.quant_stats(k, delta))
+            return (k.astype(jnp.float32) * delta).astype(g.dtype)
     if spec.variant == VARIANT_ROW:
         out = rowdither.row_dither(g, key, knobs[KNOB_ROW_ALPHA])
         if spec.collect_stats:
@@ -236,6 +259,11 @@ def _kernelops():
     return ops
 
 
+# Columns of the tally's cotangent, in order (``Trainer._step`` names them
+# ``dither_<field>`` in the step's metrics).
+TALLY_FIELDS = ("tiles_live", "tiles", "zeros", "elements")
+
+
 def _emit_kernel_stats(q, g2d: jax.Array, spec: StaticSpec, name: str):
     """Telemetry from the SAME quantized tensor the kernels consume.
 
@@ -250,8 +278,24 @@ def _emit_kernel_stats(q, g2d: jax.Array, spec: StaticSpec, name: str):
         statslib.emit(spec.stats_tag + name, nsd.quant_stats(k_live, q.delta))
 
 
+def _tile_counts(q, masks=None) -> jax.Array:
+    """The tally's cotangent for one quantized cotangent ``q``: the live
+    tiles of ``masks`` (the tile masks the matmuls consume; default
+    ``[q.mask]``), all their tiles, and the zero levels and elements of
+    ``q``'s live region. Reductions of tile maps only: the padding
+    quantizes to zero, so ``q.nnz`` counts the live region's non-zeros."""
+    masks = [q.mask] if masks is None else masks
+    with annotate("tally"):
+        m, n = q.shape
+        live = sum(jnp.sum(mk, dtype=jnp.int32) for mk in masks)
+        zeros = m * n - jnp.sum(q.nnz, dtype=jnp.int32)
+        return jnp.stack([live, sum(mk.size for mk in masks), zeros,
+                          m * n]).astype(jnp.float32)
+
+
 def _dense_kernel_bwd(x, w, key, knobs, spec, name, g):
-    """Tile-skipping backward for y = x @ w (any shape; padded to tiles)."""
+    """Tile-skipping backward for y = x @ w (any shape; padded to tiles).
+    Returns (dx, dw, tile counts)."""
     ops = _kernelops()
     kdim = x.shape[-1]
     g2d = g.reshape(-1, g.shape[-1])
@@ -259,7 +303,8 @@ def _dense_kernel_bwd(x, w, key, knobs, spec, name, g):
     _emit_kernel_stats(q, g2d, spec, name)
     dx2d, dw = ops.bsp_backward_from_quantized(
         q, x.reshape(-1, kdim), w, int8_operands=True)
-    return dx2d.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype)
+    return (dx2d.reshape(x.shape).astype(x.dtype), dw.astype(w.dtype),
+            _tile_counts(q))
 
 
 @functools.lru_cache(maxsize=None)
@@ -298,7 +343,7 @@ def _conv_kernel_bwd(strides, padding, lhs_dilation, rhs_dilation,
             q, cols.reshape(-1, kk), w_mat, int8_operands=True)
         dx = unpatch(dcols2d.reshape(cols.shape))[0]
         dw = dw_mat.reshape(ci, kh, kw, co).transpose(1, 2, 0, 3)
-        return dx.astype(x.dtype), dw.astype(w.dtype)
+        return dx.astype(x.dtype), dw.astype(w.dtype), _tile_counts(q)
 
     return kernel_bwd
 
@@ -351,16 +396,17 @@ def _einsum_kernel_bwd(spec_str: str):
         _emit_kernel_stats(q_full, g2d, spec, name)
         k3 = q_full.k[: g2d.shape[0], :fdim].reshape(n_b, -1, fdim)
         x3 = x.reshape(n_b, -1, x.shape[-1])
-        dxs, dws = [], []
+        dxs, dws, masks = [], [], []
         for e in range(n_b):
             q_e = ops.quantized_from_indices(k3[e], q_full.delta)
             dx_e, dw_e = ops.bsp_backward_from_quantized(
                 q_e, x3[e], w[e], int8_operands=True)
             dxs.append(dx_e)
             dws.append(dw_e)
+            masks.append(q_e.mask)
         dx = jnp.stack(dxs).reshape(x.shape).astype(x.dtype)
         dw = jnp.stack(dws).astype(w.dtype)
-        return dx, dw
+        return dx, dw, _tile_counts(q_full, masks)
 
     return kernel_bwd
 
@@ -376,32 +422,37 @@ def _make_dithered_op(primal_fn: Callable,
     and pushes it through the *exact* vjp of the primal — this is precisely
     the paper's recipe and is correct for any linear primal.
 
-    ``kernel_bwd(x, w, key, knobs, spec, name, g) -> (dx, dw) | None``
-    supplies the VARIANT_KERNEL tile-skipping backward; returning None
-    (a counted structural fallback) drops to the generic quantized path.
+    ``kernel_bwd(x, w, key, knobs, spec, name, g) -> (dx, dw, counts) |
+    None`` supplies the VARIANT_KERNEL tile-skipping backward, ``counts``
+    being the tally's cotangent; returning None (a counted structural
+    fallback) drops to the generic quantized path.
     """
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-    def op(x, w, key, knobs, spec, name):
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+    def op(x, w, key, knobs, tally, spec, name):
         return primal_fn(x, w)
 
-    def fwd(x, w, key, knobs, spec, name):
+    def fwd(x, w, key, knobs, tally, spec, name):
         enc = encode_residual(x, key, spec, name)
-        return primal_fn(x, w), (enc, w, key, knobs)
+        return primal_fn(x, w), (enc, w, key, knobs, tally)
 
-    def bwd(spec, name, res, g):
-        enc, w, key, knobs = res
+    def rule(spec, name, enc, w, key, knobs, g):
         x = decode_residual(enc, spec)
         if spec.variant == VARIANT_KERNEL and kernel_bwd is not None \
                 and spec.grad_codec is None:
             out = kernel_bwd(x, w, key, knobs, spec, name, g)
             if out is not None:
-                dx, dw = out
-                return dx, dw, None, None
+                return out
         gq = quantize_cotangent(g, key, knobs, spec, name)
         _, vjp = jax.vjp(primal_fn, x, w)
         dx, dw = vjp(gq)
-        return dx, dw, None, None
+        return dx, dw, None
+
+    def bwd(spec, name, res, g):
+        enc, w, key, knobs, tally = res
+        with annotate("dither/bwd"):
+            dx, dw, counts = rule(spec, name, enc, w, key, knobs, g)
+        return dx, dw, None, None, _tally_cotangent(tally, counts)
 
     op.defvjp(fwd, bwd)
     return op
@@ -418,18 +469,33 @@ def _plain_matmul(x, w):
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _dithered_dense(x, w, key, knobs, spec, name):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _dithered_dense(x, w, key, knobs, tally, spec, name):
     return _plain_matmul(x, w)
 
 
-def _dd_fwd(x, w, key, knobs, spec, name):
+def _dd_fwd(x, w, key, knobs, tally, spec, name):
     enc = encode_residual(x, key, spec, name)
-    return _plain_matmul(x, w), (enc, w, key, knobs)
+    return _plain_matmul(x, w), (enc, w, key, knobs, tally)
 
 
 def _dd_bwd(spec, name, res, g):
-    enc, w, key, knobs = res
+    enc, w, key, knobs, tally = res
+    with annotate("dither/bwd"):
+        dx, dw, counts = _dense_rule(spec, name, enc, w, key, knobs, g)
+    return dx, dw, None, None, _tally_cotangent(tally, counts)
+
+
+def _tally_cotangent(tally, counts):
+    """The tally's cotangent: the kernel path's counts, else none."""
+    if tally is None or counts is None:
+        return None
+    return counts
+
+
+def _dense_rule(spec, name, enc, w, key, knobs, g):
+    """The dense backward under the layer's variant: (dx, dw, counts),
+    counts being None off the kernel path."""
     x = decode_residual(enc, spec)
     s = knobs[KNOB_S]
     kdim = x.shape[-1]
@@ -443,34 +509,32 @@ def _dd_bwd(spec, name, res, g):
         # (interpret mode on CPU; compiled VMEM kernels on TPU). Any layer
         # shape: operands are zero-padded to tile multiples, the padding
         # tiles quantize to all-zero and are masked off.
-        dx, dw = _dense_kernel_bwd(x, w, key, knobs, spec, name, g)
-        return dx, dw, None, None
+        return _dense_kernel_bwd(x, w, key, knobs, spec, name, g)
 
     if spec.variant == VARIANT_INT8 and spec.grad_codec is None:
         # NSD indices ARE an int8 tensor; x and w get absmax int8. Both
         # backward products then run on the int8 MXU path (2x bf16 on v5e).
-        delta = nsd.compute_delta(g2d, s)
-        k = nsd.nsd_indices(g2d, key, delta).astype(jnp.int8)
-        if spec.collect_stats:
-            statslib.emit(spec.stats_tag + name, nsd.quant_stats(k, delta))
-        xq = int8lib._quantize_int8(x2d)
-        wq = int8lib._quantize_int8(w)
-        # dx = g~ @ W^T : contract over the output dim
-        dx2d = jax.lax.dot_general(
-            k, wq.q, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        ).astype(jnp.float32) * (delta * wq.scale)
-        # dW = x^T @ g~ : contract over the row (token) dim
-        dw = jax.lax.dot_general(
-            xq.q, k, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        ).astype(jnp.float32) * (xq.scale * delta)
-        return (
-            dx2d.astype(x.dtype).reshape(x.shape),
-            dw.astype(w.dtype),
-            None,
-            None,
-        )
+        with annotate("nsd"):
+            delta = nsd.compute_delta(g2d, s)
+            k = nsd.nsd_indices(g2d, key, delta).astype(jnp.int8)
+            if spec.collect_stats:
+                statslib.emit(spec.stats_tag + name,
+                              nsd.quant_stats(k, delta))
+        with annotate("matmul"):
+            xq = int8lib._quantize_int8(x2d)
+            wq = int8lib._quantize_int8(w)
+            # dx = g~ @ W^T : contract over the output dim
+            dx2d = jax.lax.dot_general(
+                k, wq.q, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            ).astype(jnp.float32) * (delta * wq.scale)
+            # dW = x^T @ g~ : contract over the row (token) dim
+            dw = jax.lax.dot_general(
+                xq.q, k, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            ).astype(jnp.float32) * (xq.scale * delta)
+        return (dx2d.astype(x.dtype).reshape(x.shape), dw.astype(w.dtype),
+                None)
 
     gq = quantize_cotangent(g2d, key, knobs, spec, name)
     dx2d = jax.lax.dot_general(
@@ -481,8 +545,7 @@ def _dd_bwd(spec, name, res, g):
         x2d, gq, dimension_numbers=(((0,), (0,)), ((), ())),
         preferred_element_type=x2d.dtype,
     )
-    return dx2d.astype(x.dtype).reshape(x.shape), dw.astype(w.dtype), None, \
-        None
+    return dx2d.astype(x.dtype).reshape(x.shape), dw.astype(w.dtype), None
 
 
 _dithered_dense.defvjp(_dd_fwd, _dd_bwd)
@@ -505,7 +568,7 @@ def dense(
     r = ctx.resolve(name) if ctx is not None else None
     if r is not None:
         _record_footprint(ctx, r, name, x)
-        y = _apply_op(_dithered_dense, x, w, r, name)
+        y = _apply_op(_dithered_dense, x, w, r, name, _tally_for(ctx, name))
     else:
         y = _plain_matmul(x, w)
     if b is not None:
@@ -557,7 +620,8 @@ def conv2d(
     r = ctx.resolve(name) if ctx is not None else None
     if r is not None:
         _record_footprint(ctx, r, name, x)
-        y = _apply_op(_make_dithered_op(primal, kernel_bwd), x, w, r, name)
+        y = _apply_op(_make_dithered_op(primal, kernel_bwd), x, w, r, name,
+                      _tally_for(ctx, name))
     else:
         y = primal(x, w)
     if b is not None:
@@ -590,5 +654,5 @@ def dithered_einsum(
     if r is not None:
         _record_footprint(ctx, r, name, x)
         return _apply_op(_make_dithered_op(primal, _einsum_kernel_bwd(spec)),
-                         x, w, r, name)
+                         x, w, r, name, _tally_for(ctx, name))
     return primal(x, w)

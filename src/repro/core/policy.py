@@ -222,6 +222,10 @@ class DitherCtx:
     # trace-time residual-footprint recorder: {name: (stored, dense) bytes}
     # (repro.memory.accounting.residual_report)
     mem_recorder: Optional[Dict[str, tuple]] = None
+    # {layer name: zero f32 (4,)}; each one's cotangent counts that layer's
+    # kernel-path work (repro.core.dithered.TALLY_FIELDS); None = nothing
+    # counted
+    tally: Optional[Dict[str, jax.Array]] = None
 
     def key_for(self, name: str) -> jax.Array:
         return jax.random.fold_in(self.key, name_salt(name))

@@ -18,6 +18,12 @@ for free (no silent dense fallback remains — structural fallbacks that do
 survive, e.g. unsupported einsum forms, are counted in
 ``KERNEL_FALLBACKS``). ``interpret=None`` resolves backend-aware: interpret
 off-TPU, compiled on TPU (``repro.kernels.backend``).
+
+Each pass runs under a named scope, so a device profile can attribute the
+backward's time: ``noise`` (Delta, the dither draw, padding), ``nsd`` (the
+fused quantize kernel), ``pack`` (bitmap and tile mask) and ``matmul``
+(operand absmax, transposes and both products). ``repro.core.dithered``
+wraps them in ``dither/bwd``.
 """
 from __future__ import annotations
 
@@ -31,6 +37,7 @@ from repro.kernels.backend import default_interpret
 from repro.kernels.bsp_matmul.bsp_matmul import bsp_matmul, bsp_matmul_int8
 from repro.kernels.nsd_quant.nsd_quant import nsd_quantize_blocked
 from repro.kernels.pack.pack import bitmap_pack_blocked
+from repro.obs.trace import annotate
 from repro.quant import wire as wireformat
 from repro.quant.codecs import absmax_int8
 
@@ -83,12 +90,14 @@ def nsd_quantize_kernel(g: jax.Array, key: jax.Array, s, *,
     """
     interpret = default_interpret(interpret)
     M, N = g.shape
-    delta = nsd.compute_delta(g, s)
-    noise = nsd.dither_noise(key, g.shape, delta)
-    gp = _pad_to(g, bm, bn)
-    np_ = _pad_to(noise, bm, bn)
-    k, nnz = nsd_quantize_blocked(gp, np_, delta, bm=bm, bn=bn,
-                                  interpret=interpret)
+    with annotate("noise"):
+        delta = nsd.compute_delta(g, s)
+        noise = nsd.dither_noise(key, g.shape, delta)
+        gp = _pad_to(g, bm, bn)
+        np_ = _pad_to(noise, bm, bn)
+    with annotate("nsd"):
+        k, nnz = nsd_quantize_blocked(gp, np_, delta, bm=bm, bn=bn,
+                                      interpret=interpret)
     return k[:M, :N], delta, nnz
 
 
@@ -105,15 +114,18 @@ def quantize_and_mask(g: jax.Array, key: jax.Array, s, *,
     """
     interpret = default_interpret(interpret)
     M, N = g.shape
-    delta = nsd.compute_delta(g, s)
-    noise = nsd.dither_noise(key, g.shape, delta)
-    gp = _pad_to(g, block, block)
-    np_ = _pad_to(noise, block, block)
-    k, nnz = nsd_quantize_blocked(gp, np_, delta, bm=block, bn=block,
-                                  interpret=interpret)
-    bitmap, _ = bitmap_pack_blocked(k, bm=block, bn=block,
-                                    interpret=interpret)
-    mask = wireformat.tile_mask_from_bitmap(bitmap, block, block)
+    with annotate("noise"):
+        delta = nsd.compute_delta(g, s)
+        noise = nsd.dither_noise(key, g.shape, delta)
+        gp = _pad_to(g, block, block)
+        np_ = _pad_to(noise, block, block)
+    with annotate("nsd"):
+        k, nnz = nsd_quantize_blocked(gp, np_, delta, bm=block, bn=block,
+                                      interpret=interpret)
+    with annotate("pack"):
+        bitmap, _ = bitmap_pack_blocked(k, bm=block, bn=block,
+                                        interpret=interpret)
+        mask = wireformat.tile_mask_from_bitmap(bitmap, block, block)
     return QuantizedGrad(k=k, delta=delta, nnz=nnz, bitmap=bitmap,
                          mask=mask, shape=(M, N))
 
@@ -130,11 +142,12 @@ def quantized_from_indices(k: jax.Array, delta: jax.Array, *,
     """
     interpret = default_interpret(interpret)
     M, N = k.shape
-    kp = _pad_to(k.astype(jnp.int8), block, block)
-    bitmap, _ = bitmap_pack_blocked(kp, bm=block, bn=block,
-                                    interpret=interpret)
-    mask = wireformat.tile_mask_from_bitmap(bitmap, block, block)
-    nnz = wireformat.tile_nnz_from_bitmap(bitmap, block, block)
+    with annotate("pack"):
+        kp = _pad_to(k.astype(jnp.int8), block, block)
+        bitmap, _ = bitmap_pack_blocked(kp, bm=block, bn=block,
+                                        interpret=interpret)
+        mask = wireformat.tile_mask_from_bitmap(bitmap, block, block)
+        nnz = wireformat.tile_nnz_from_bitmap(bitmap, block, block)
     return QuantizedGrad(k=kp, delta=delta, nnz=nnz, bitmap=bitmap,
                          mask=mask, shape=(M, N))
 
@@ -153,28 +166,30 @@ def bsp_backward_from_quantized(
     interpret = default_interpret(interpret)
     T, N = q.shape
     K = x.shape[-1]
-    x2d = _pad_to(x.reshape(-1, K), block, block)
-
-    if int8_operands:
-        wq = absmax_int8(w)
-        xq = absmax_int8(x.reshape(-1, K))
-        # dx = g~ @ w^T : tiles of g~ index rows; mask transposes with g~
-        dx = bsp_matmul_int8(
-            q.k, _pad_to(wq.q.T, block, block), q.delta * wq.scale, q.mask,
-            bm=block, bk=block, bn=block, interpret=interpret)
-        # dw = x^T @ g~ = (g~^T @ x)^T; mask for g~^T is mask^T
-        dw_t = bsp_matmul_int8(
-            q.k.T, _pad_to(xq.q, block, block), q.delta * xq.scale,
-            q.mask.T, bm=block, bk=block, bn=block, interpret=interpret)
-    else:
-        dx = bsp_matmul(q.k, q.delta,
-                        _pad_to(w.T.astype(jnp.float32), block, block),
-                        q.mask, bm=block, bk=block, bn=block,
-                        interpret=interpret)
-        dw_t = bsp_matmul(q.k.T, q.delta, x2d.astype(jnp.float32), q.mask.T,
-                          bm=block, bk=block, bn=block, interpret=interpret)
-    return (dx[:T, :K].astype(x.dtype),
-            dw_t[:N, :K].T.astype(w.dtype))
+    x2d = x.reshape(-1, K)
+    with annotate("matmul"):
+        if int8_operands:
+            wq = absmax_int8(w)
+            xq = absmax_int8(x2d)
+            # dx = g~ @ w^T : tiles of g~ index rows; mask transposes with g~
+            dx = bsp_matmul_int8(
+                q.k, _pad_to(wq.q.T, block, block), q.delta * wq.scale,
+                q.mask, bm=block, bk=block, bn=block, interpret=interpret)
+            # dw = x^T @ g~ = (g~^T @ x)^T; mask for g~^T is mask^T
+            dw_t = bsp_matmul_int8(
+                q.k.T, _pad_to(xq.q, block, block), q.delta * xq.scale,
+                q.mask.T, bm=block, bk=block, bn=block, interpret=interpret)
+        else:
+            dx = bsp_matmul(q.k, q.delta,
+                            _pad_to(w.T.astype(jnp.float32), block, block),
+                            q.mask, bm=block, bk=block, bn=block,
+                            interpret=interpret)
+            dw_t = bsp_matmul(q.k.T, q.delta,
+                              _pad_to(x2d, block, block).astype(jnp.float32),
+                              q.mask.T, bm=block, bk=block, bn=block,
+                              interpret=interpret)
+        return (dx[:T, :K].astype(x.dtype),
+                dw_t[:N, :K].T.astype(w.dtype))
 
 
 def dithered_backward_matmuls(

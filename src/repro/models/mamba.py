@@ -7,6 +7,14 @@ sub-quadratic family that carries the ``long_500k`` shape cells.
 Dithered backprop covers the in/out projections (the FLOP-dominant dense
 matmuls). The state recurrence itself is elementwise and stays exact — see
 DESIGN.md §5 (mamba2 row).
+
+The training forward names its parts with ``repro.obs.trace.annotate``
+scopes, so a device profile attributes each op (forward, remat recompute
+and backward alike): ``embed``, ``layers`` (the scan over the blocks),
+``block/norm`` (pre-mixer norm and the residual add), ``mixer/in_proj``,
+``mixer/conv``, ``mixer/ssd`` (chunked scan, ``D`` skip, gated norm),
+``mixer/out_proj`` and ``head`` (final norm, unembedding, log-softmax and
+NLL).
 """
 from __future__ import annotations
 
@@ -21,6 +29,7 @@ from repro.core import dense
 from repro.core.policy import DitherCtx
 from repro.core.probe import tap
 from repro.models import layers as L
+from repro.obs.trace import annotate
 from repro.parallel.axes import shard_act
 
 
@@ -179,9 +188,15 @@ def mamba_mixer(params: L.Params, x: jax.Array, cfg: SSMConfig, *,
                 ctx: Optional[DitherCtx] = None, name: str = "ssm",
                 taps=None) -> jax.Array:
     """Full Mamba-2 mixer for train/prefill. x: (B,S,d_model)."""
+    with annotate("mixer"):
+        return _mixer(params, x, cfg, ctx=ctx, name=name, taps=taps)
+
+
+def _mixer(params, x, cfg: SSMConfig, *, ctx, name, taps):
     B, S, _ = x.shape
     H, Pd, G, N = cfg.n_heads, cfg.head_dim, cfg.n_groups, cfg.d_state
-    zxbcdt = dense(x, params["in_proj"], ctx=ctx, name=f"{name}.in")
+    with annotate("in_proj"):
+        zxbcdt = dense(x, params["in_proj"], ctx=ctx, name=f"{name}.in")
     zxbcdt = tap(zxbcdt, taps, f"{name}.in_out")
     z, xs, Bm, Cm, dt = jnp.split(
         zxbcdt,
@@ -190,8 +205,9 @@ def mamba_mixer(params: L.Params, x: jax.Array, cfg: SSMConfig, *,
         axis=-1,
     )
     conv_in = jnp.concatenate([xs, Bm, Cm], axis=-1)
-    conv_out = jax.nn.silu(
-        _causal_conv(conv_in, params["conv_w"], params["conv_b"]))
+    with annotate("conv"):
+        conv_out = jax.nn.silu(
+            _causal_conv(conv_in, params["conv_w"], params["conv_b"]))
     xs, Bm, Cm = jnp.split(
         conv_out, [cfg.d_inner, cfg.d_inner + G * N], axis=-1)
     xs = xs.reshape(B, S, H, Pd)
@@ -200,13 +216,15 @@ def mamba_mixer(params: L.Params, x: jax.Array, cfg: SSMConfig, *,
     A = -jnp.exp(params["A_log"].astype(jnp.float32))
     dt = jax.nn.softplus(dt.astype(jnp.float32) +
                          params["dt_bias"].astype(jnp.float32))
-    y, _ = _ssd_chunked(xs, dt, A, Bm, Cm, cfg)
-    y = y + params["D"].astype(jnp.float32)[None, None, :, None] * \
-        xs.astype(jnp.float32)
-    y = y.reshape(B, S, cfg.d_inner).astype(x.dtype)
-    y = L.rms_norm(y * jax.nn.silu(z), params["norm"])
+    with annotate("ssd"):
+        y, _ = _ssd_chunked(xs, dt, A, Bm, Cm, cfg)
+        y = y + params["D"].astype(jnp.float32)[None, None, :, None] * \
+            xs.astype(jnp.float32)
+        y = y.reshape(B, S, cfg.d_inner).astype(x.dtype)
+        y = L.rms_norm(y * jax.nn.silu(z), params["norm"])
     y = shard_act(y, ("batch", "seq", "act_ssm_inner"))
-    return dense(y, params["out_proj"], ctx=ctx, name=f"{name}.out")
+    with annotate("out_proj"):
+        return dense(y, params["out_proj"], ctx=ctx, name=f"{name}.out")
 
 
 class MambaCache:
@@ -323,38 +341,50 @@ def init_ssm_lm(key: jax.Array, cfg: SSMLMConfig) -> Tuple[L.Params, L.Specs]:
             {"embed": emb_s, "layers": stacked_s, "head": head_s})
 
 
+def _block(x, p, cfg: SSMLMConfig, *, ctx, name, taps=None):
+    """One residual block: pre-mixer norm, mixer, residual add."""
+    with annotate("block/norm"):
+        h = L.rms_norm(x, p["ln"])
+    y = mamba_mixer(p["mixer"], h, cfg.ssm, ctx=ctx, name=name, taps=taps)
+    with annotate("block/norm"):
+        return x + y
+
+
 def forward(params, cfg: SSMLMConfig, tokens: jax.Array, *,
             ctx: Optional[DitherCtx] = None, taps=None):
-    x = L.embed(params["embed"], tokens)
+    with annotate("embed"):
+        x = L.embed(params["embed"], tokens)
 
     if taps is not None:
         for i in range(cfg.n_layers):
             p = L.layer_slice(params["layers"], i)
-            h = L.rms_norm(x, p["ln"])
-            x = x + mamba_mixer(p["mixer"], h, cfg.ssm, ctx=ctx,
-                                name=f"L{i}.ssm", taps=taps)
+            x = _block(x, p, cfg, ctx=ctx, name=f"L{i}.ssm", taps=taps)
     else:
         def body(x, p):
-            h = L.rms_norm(x, p["ln"])
-            return x + mamba_mixer(p["mixer"], h, cfg.ssm, ctx=ctx,
-                                   name="L.ssm"), None
+            return _block(x, p, cfg, ctx=ctx, name="L.ssm"), None
 
         f = body
         if cfg.remat:
             f = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-        x, _ = jax.lax.scan(f, x, params["layers"],
-                            unroll=cfg.n_layers if cfg.scan_unroll else 1)
+        # the scan's own ops (stacked-gradient updates, the layout copies
+        # XLA inserts without a name stack) take the loop's name
+        with annotate("layers"):
+            x, _ = jax.lax.scan(f, x, params["layers"],
+                                unroll=cfg.n_layers if cfg.scan_unroll else 1)
 
-    x = L.rms_norm(x, params["head"]["ln_f"])
-    logits = L.unembed(params["embed"], x, ctx=ctx)
+    with annotate("head"):
+        x = L.rms_norm(x, params["head"]["ln_f"])
+        logits = L.unembed(params["embed"], x, ctx=ctx)
     return logits, jnp.zeros((), jnp.float32)
 
 
 def loss_fn(params, cfg: SSMLMConfig, batch, *, ctx=None, taps=None):
     logits, _ = forward(params, cfg, batch["tokens"], ctx=ctx, taps=taps)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+    with annotate("head"):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, batch["labels"][..., None],
+                                   axis=-1)[..., 0]
+        return jnp.mean(nll)
 
 
 def init_cache(cfg: SSMLMConfig, batch: int, max_len: int, dtype=None):

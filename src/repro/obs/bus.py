@@ -67,9 +67,18 @@ class MetricsBus:
 
     # ------------------------------------------------------------- writers
     def record(self, stream: str, tag: str, row) -> None:
-        """Host-side append of one row (also the io_callback landing pad)."""
+        """Host-side append of one row (also the io_callback landing pad).
+
+        A device array (``jax.Array``) is kept as it is and reaches the
+        host only when a reader stacks it, so a producer can record a
+        step's on-device values without waiting for the step."""
+        import jax
+
         spec = self.registry.get(stream)
-        arr = np.asarray(row, np.float32).reshape(-1)
+        if isinstance(row, jax.Array):
+            arr = row.astype(np.float32).reshape(-1)
+        else:
+            arr = np.asarray(row, np.float32).reshape(-1)
         if arr.shape != (spec.ncols,):
             raise ValueError(
                 f"stream {stream!r} expects {spec.ncols} columns "

@@ -109,8 +109,17 @@ FALLBACK = MetricStream(
     "cumulative trace-time kernel-path fallback counts "
     "(repro.kernels.ops.KERNEL_FALLBACKS), snapshotted at run end")
 
+# one row per Trainer.fit call that ran a dithered step; tag = "train"
+TALLY = MetricStream(
+    "tally", ("tiles_live", "tiles", "zeros", "elements"),
+    "the kernel path's work in the last step of a fit call "
+    "(repro.core.dithered.TALLY_FIELDS): live 128 x 128 tiles of the "
+    "quantized cotangents, tiles of their padded grids, zero levels and "
+    "elements of their live regions, summed over layers; recorded as the "
+    "step's device array, read on the host only by a reader")
+
 BUILTIN_STREAMS = (DITHER, COMM, MEMORY, PHASE, TRAIN, BOUND, SERVE,
-                   OVERLAP, FALLBACK)
+                   OVERLAP, FALLBACK, TALLY)
 
 
 class StreamRegistry:

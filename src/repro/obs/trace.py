@@ -24,9 +24,31 @@ The span taxonomy used by the built-in drivers:
 * ``admit`` / ``decode`` — serving-engine tick phases
 * ``lower`` / ``compile`` — dry-run cell phases
 
+``Trainer.fit`` opens its ``data`` / ``dispatch`` / ``controller`` /
+``checkpoint`` spans on every step: through the run observer's tracer when a
+``RunObs`` is attached, else through :func:`profile_span`, which opens the
+profiler annotation alone (no bus row, no host sync), so the spans land on
+the profiler's clock in every run. Each step also runs inside
+:func:`step_span` (a ``jax.profiler.StepTraceAnnotation`` named ``train``
+with the step number).
+
 Inside *jitted* code host spans cannot run; use :func:`annotate` (a thin
-``jax.named_scope``) there, which names the HLO region so device profiles
-attribute time to the same taxonomy.
+``jax.named_scope``) there, which names the HLO region: each device op of a
+profile carries its name stack in the ``tf_op`` stat of its event metadata,
+so device time is attributed to the same taxonomy. The scopes in the step
+program:
+
+* ``step/grad`` / ``step/comm`` / ``step/update`` — ``Trainer._step``
+* ``embed``, ``layers``, ``block/norm``, ``mixer/in_proj``,
+  ``mixer/conv``, ``mixer/ssd``, ``mixer/out_proj``, ``head`` — the
+  Mamba-2 model (``repro.models.mamba``)
+* ``dither/bwd`` — every dithered backward rule (``repro.core.dithered``),
+  with ``noise``, ``nsd``, ``pack``, ``matmul`` and ``tally`` inside it
+  (``repro.kernels.ops`` for the kernel path)
+
+A scope is HLO metadata: it changes no op, and JAX's persistent
+compilation cache ignores it unless :func:`keep_scopes_in_compile_cache`
+puts it in the key, as the ``Trainer`` does.
 
 The module-level :func:`span` uses the process-default tracer, whose step
 counter the training/serving loops advance with :func:`set_step`.
@@ -114,3 +136,31 @@ def span(name: str):
 
 def set_step(step: int) -> None:
     _DEFAULT.set_step(step)
+
+
+def keep_scopes_in_compile_cache() -> None:
+    """Key JAX's persistent compilation cache on the programs' metadata.
+
+    By default the key strips the name stacks, so a program that differs
+    from a cached one only in its scopes is handed the cached executable,
+    and a profile of it shows the other program's names. Programs whose
+    device time is attributed by scope turn this on.
+    """
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+
+def profile_span(name: str):
+    """A span on the profiler's clock alone: a ``TraceAnnotation`` with no
+    bus row and no timing, for loops that run without a run observer."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+def step_span(step: int, name: str = "train"):
+    """Marks one step of a loop in the profile (its step number too)."""
+    import jax
+
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)

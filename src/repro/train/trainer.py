@@ -5,7 +5,6 @@ distribution enters only through the sharding rules installed around jit.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, Iterator, Optional
@@ -15,10 +14,15 @@ import jax.numpy as jnp
 
 from repro.comm.compression import CommPolicy, init_comm_state
 from repro.comm.reducer import reducer as comm_reducer
+from repro.core.dithered import TALLY_FIELDS
 from repro.core.policy import DitherCtx, DitherPolicy
-from repro.core.schedule import ControllerDriver, PolicyProgram, as_program
+from repro.core.schedule import (ControllerDriver, PolicyProgram, as_program,
+                                 discover_layer_names)
 from repro.models.api import Model
-from repro.obs.trace import annotate
+from repro.obs.bus import get_bus
+from repro.obs.streams import TALLY
+from repro.obs.trace import (annotate, keep_scopes_in_compile_cache,
+                              profile_span, step_span)
 from repro.optim import OptConfig, apply_updates, init_opt_state
 from repro.train.checkpoint import CheckpointManager
 from repro.train.fault_tolerance import PreemptionGuard
@@ -93,6 +97,9 @@ class Trainer:
         # before the next step runs)
         self._jit_step = jax.jit(self._step, static_argnames=("phase_policy",),
                                  donate_argnames=("params", "opt_state"))
+        # the step's named scopes attribute its device time in a profile:
+        # a cached executable of another version must not stand in for it
+        keep_scopes_in_compile_cache()
         self.history: list = []
 
     # one optimizer step with optional micro-batch gradient accumulation
@@ -106,17 +113,20 @@ class Trainer:
                                      ctrl=ctrl_state or None,
                                      memory=self.memory_policy)
 
-        def one_loss(p, b, i):
+        def one_loss(p, t, b, i):
             c = None
             if ctx is not None:
                 # micro-batches get distinct noise: fold the slice index in
-                c = ctx.with_key(jax.random.fold_in(ctx.key, i))
+                c = dataclasses.replace(
+                    ctx.with_key(jax.random.fold_in(ctx.key, i)), tally=t)
             return self.model.loss(p, b, ctx=c)
 
+        grad_fn = jax.value_and_grad(one_loss, argnums=(0, 1))
         n = self.tcfg.grad_accum
         if n == 1:
+            tally = self._tally(ctx, params, batches)
             with annotate("step/grad"):
-                loss, grads = jax.value_and_grad(one_loss)(params, batches, 0)
+                loss, (grads, counts) = grad_fn(params, tally, batches, 0)
         else:
             # accept flat batches: split the leading (batch) dim into
             # (n, batch/n, ...) microbatches
@@ -127,19 +137,24 @@ class Trainer:
                 return x.reshape((n, x.shape[0] // n) + x.shape[1:])
 
             batches = jax.tree.map(to_micro, batches)
+            tally = self._tally(ctx, params,
+                                jax.tree.map(lambda x: x[0], batches))
 
             def acc_fn(carry, ib):
                 i, b = ib
-                lv, g = jax.value_and_grad(one_loss)(params, b, i)
-                loss_acc, g_acc = carry
+                lv, (g, c) = grad_fn(params, tally, b, i)
+                loss_acc, g_acc, c_acc = carry
+                # the counts sum over micro-batches: each ran its backward
                 return (loss_acc + lv / n,
-                        jax.tree.map(lambda a, x: a + x / n, g_acc, g)), None
+                        jax.tree.map(lambda a, x: a + x / n, g_acc, g),
+                        jax.tree.map(jnp.add, c_acc, c)), None
 
             zero = (jnp.zeros(()),
                     jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
-                                 params))
+                                 params),
+                    tally)
             with annotate("step/grad"):
-                (loss, grads), _ = jax.lax.scan(
+                (loss, grads, counts), _ = jax.lax.scan(
                     acc_fn, zero, (jnp.arange(n), batches))
         if self._reducer is not None:
             # the reducer folds the step in; the 0xC033 salt keeps the
@@ -158,7 +173,27 @@ class Trainer:
                 params, grads, opt_state, self.opt_cfg)
         metrics["loss"] = loss
         metrics.update(metrics_comm)
+        if counts is not None:
+            total = sum(jax.tree.leaves(counts))
+            metrics.update({f"dither_{k}": total[i]
+                            for i, k in enumerate(TALLY_FIELDS)})
         return params, opt_state, metrics, comm_state
+
+    def _tally(self, ctx, params, batch):
+        """Zero tally inputs, one per layer name that consults the policy
+        (found by an abstract trace of the loss), or None without a ctx.
+        Their gradients count the kernel path's work
+        (repro.core.dithered.TALLY_FIELDS); the loss and the other
+        gradients do not depend on them."""
+        if ctx is None:
+            return None
+        shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape,
+                                                             a.dtype),
+                              (params, batch))
+        names = discover_layer_names(
+            lambda p, b, c: self.model.loss(p, b, ctx=c), *shapes)
+        return {name: jnp.zeros((len(TALLY_FIELDS),), jnp.float32)
+                for name in names}
 
     def _init_comm_state(self, params) -> Dict[str, Any]:
         return (init_comm_state(params, self.comm_policy)
@@ -232,6 +267,15 @@ class Trainer:
 
     def fit(self, batch_iter: Iterator, params=None, opt_state=None
             ) -> Dict[str, Any]:
+        """Train to ``tcfg.total_steps``.
+
+        Returns ``params``, ``opt_state``, ``history`` (the logged rows) and
+        ``metrics``: the last step's metrics as device arrays (None when no
+        step ran), read without a host sync. A dithered step's kernel-path
+        counters (``dither_tiles_live``, ``dither_tiles``, ``dither_zeros``,
+        ``dither_elements``) are among them and are also recorded, unread,
+        as one row of the bus's ``tally`` stream per call.
+        """
         key = jax.random.PRNGKey(self.tcfg.seed)
         base_key = jax.random.fold_in(key, 0xD17E)
         if params is None:
@@ -241,71 +285,75 @@ class Trainer:
             self._comm_state = self._init_comm_state(params)
         comm_state = self._comm_state
         # span factory: with obs attached every phase is timed into the
-        # "phase" stream; without it the loop stays observability-free
-        if self.obs is not None:
-            sp = self.obs.span
-        else:
-            def sp(name):
-                return contextlib.nullcontext()
+        # "phase" stream; without it the spans only mark the profiler's
+        # timeline (no bus row, no host sync)
+        sp = self.obs.span if self.obs is not None else profile_span
+        metrics = None
         t0 = time.time()
         for step in range(start, self.tcfg.total_steps):
-            if self.obs is not None:
-                self.obs.set_step(step)
-            if self.guard.should_stop:
-                log.info("preemption: checkpointing at step %d and exiting",
-                         step)
-                if self.ckpt is not None:
+            with step_span(step):
+                if self.obs is not None:
+                    self.obs.set_step(step)
+                if self.guard.should_stop:
+                    log.info("preemption: checkpointing at step %d and "
+                             "exiting", step)
+                    if self.ckpt is not None:
+                        with sp("checkpoint"):
+                            self.ckpt.save(
+                                step, self._ckpt_tree(params, opt_state))
+                            self.ckpt.wait()
+                    break
+                with sp("data"):
+                    batch = next(batch_iter)
+                    if isinstance(batch, tuple):  # (step, batch) loaders
+                        batch = batch[1]
+                self._init_ctrl_state(params, batch)
+                phase_policy = self._phase_policy(step)
+                with sp("dispatch"):
+                    params, opt_state, metrics, comm_state = self._jit_step(
+                        params, opt_state, batch, base_key, comm_state,
+                        self._ctrl.state, phase_policy=phase_policy)
+                self._comm_state = comm_state
+                # controller tick: fold the step's per-layer telemetry into
+                # the log-scales (host-side; the updated state is a traced
+                # input next step, so no retrace)
+                with sp("controller"):
+                    self._ctrl.tick()
+                if self.obs is not None:
+                    # float() blocks on the step's device values —
+                    # acceptable only because obs is opt-in; monitors + run
+                    # log need host scalars
+                    self.obs.on_step(
+                        step + 1, {k: float(v) for k, v in metrics.items()})
+                if (self.tcfg.log_every
+                        and (step + 1) % self.tcfg.log_every == 0):
+                    loss = float(metrics["loss"])
+                    # time_s: wall seconds since the loop started, read once
+                    # this step's loss has reached the host
+                    row = {"step": step + 1, "loss": loss,
+                           "time_s": time.time() - t0}
+                    if "comm_wire_bytes" in metrics:
+                        wire = float(metrics["comm_wire_bytes"])
+                        row["comm_wire_mb"] = wire / 1e6
+                        if self.topology is not None:
+                            from repro.launch.costmodel import \
+                                price_step_comm
+                            row.update(price_step_comm(
+                                wire, pods=self.topology.pods))
+                    self.history.append(row)
+                    log.info("step %d loss %.4f (%.2f s)", step + 1, loss,
+                             row["time_s"])
+                if (self.ckpt is not None and self.tcfg.ckpt_every
+                        and (step + 1) % self.tcfg.ckpt_every == 0):
                     with sp("checkpoint"):
-                        self.ckpt.save(step,
+                        self.ckpt.save(step + 1,
                                        self._ckpt_tree(params, opt_state))
-                        self.ckpt.wait()
-                break
-            with sp("data"):
-                batch = next(batch_iter)
-                if isinstance(batch, tuple):  # (step, batch) loaders
-                    batch = batch[1]
-            self._init_ctrl_state(params, batch)
-            phase_policy = self._phase_policy(step)
-            with sp("dispatch"):
-                params, opt_state, metrics, comm_state = self._jit_step(
-                    params, opt_state, batch, base_key, comm_state,
-                    self._ctrl.state, phase_policy=phase_policy)
-            self._comm_state = comm_state
-            # controller tick: fold the step's per-layer telemetry into the
-            # log-scales (host-side; the updated state is a traced input
-            # next step, so no retrace)
-            with sp("controller"):
-                self._ctrl.tick()
-            if self.obs is not None:
-                # float() blocks on the step's device values — acceptable
-                # only because obs is opt-in; monitors + run log need host
-                # scalars
-                self.obs.on_step(
-                    step + 1, {k: float(v) for k, v in metrics.items()})
-            if self.tcfg.log_every and (step + 1) % self.tcfg.log_every == 0:
-                loss = float(metrics["loss"])
-                # time_s: wall seconds since the loop started, read once
-                # this step's loss has reached the host
-                row = {"step": step + 1, "loss": loss,
-                       "time_s": time.time() - t0}
-                if "comm_wire_bytes" in metrics:
-                    wire = float(metrics["comm_wire_bytes"])
-                    row["comm_wire_mb"] = wire / 1e6
-                    if self.topology is not None:
-                        from repro.launch.costmodel import price_step_comm
-                        row.update(price_step_comm(
-                            wire, pods=self.topology.pods))
-                self.history.append(row)
-                log.info("step %d loss %.4f (%.2f s)", step + 1, loss,
-                         row["time_s"])
-            if (self.ckpt is not None and self.tcfg.ckpt_every
-                    and (step + 1) % self.tcfg.ckpt_every == 0):
-                with sp("checkpoint"):
-                    self.ckpt.save(step + 1,
-                                   self._ckpt_tree(params, opt_state))
         if self.ckpt is not None:
             self.ckpt.wait()
         if self.obs is not None:
             self.obs.finish()
+        if metrics is not None and "dither_tiles" in metrics:
+            get_bus().record(TALLY.name, "train", jnp.stack(
+                [metrics[f"dither_{k}"] for k in TALLY_FIELDS]))
         return {"params": params, "opt_state": opt_state,
-                "history": self.history}
+                "history": self.history, "metrics": metrics}
