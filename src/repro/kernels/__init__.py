@@ -2,8 +2,11 @@
 
 backend.py   backend-aware interpret default (interpret off-TPU, compiled on)
 nsd_quant/   fused NSD quantize -> (int8 k, tile-occupancy map)
-bsp_matmul/  tile-skipping quantized matmuls (dequant + full-int8 variants;
-             masked tiles skip MXU issue AND operand DMA via fetch maps)
+bsp_matmul/  tile-masked quantized matmuls (dequant + full-int8 variants;
+             a masked tile contributes nothing, and a compute block with no
+             live tile skips MXU issue AND operand DMA via fetch maps; the
+             int8 variant sizes its blocks from the shapes, the dequant
+             one computes 128 x 128 tiles)
 pack/        occupancy-bitmap pack/unpack for the comm wire format
 levels/      chunk-local compact/expand of the wire's non-zero int8 levels
              (butterfly routing network; replaces the jnp full-cumsum

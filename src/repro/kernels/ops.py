@@ -174,11 +174,11 @@ def bsp_backward_from_quantized(
             # dx = g~ @ w^T : tiles of g~ index rows; mask transposes with g~
             dx = bsp_matmul_int8(
                 q.k, _pad_to(wq.q.T, block, block), q.delta * wq.scale,
-                q.mask, bm=block, bk=block, bn=block, interpret=interpret)
+                q.mask, tile=block, interpret=interpret)
             # dw = x^T @ g~ = (g~^T @ x)^T; mask for g~^T is mask^T
             dw_t = bsp_matmul_int8(
                 q.k.T, _pad_to(xq.q, block, block), q.delta * xq.scale,
-                q.mask.T, bm=block, bk=block, bn=block, interpret=interpret)
+                q.mask.T, tile=block, interpret=interpret)
         else:
             dx = bsp_matmul(q.k, q.delta,
                             _pad_to(w.T.astype(jnp.float32), block, block),

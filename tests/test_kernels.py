@@ -16,8 +16,12 @@ from repro.core import (DitherCtx, DitherPolicy, Piecewise, PolicyProgram,
                         conv2d, dense, dithered_einsum, nsd)
 from repro.obs import metrics as statslib
 from repro.kernels import ops as kernelops
-from repro.kernels.bsp_matmul.bsp_matmul import (bsp_matmul, bsp_matmul_int8,
-                                                 fetch_map)
+from repro.kernels.bsp_matmul.bsp_matmul import (_FULL, _PARTIAL, _DEAD,
+                                                 _INT8_VMEM_BUDGET,
+                                                 INT8_BLOCKS, _block_state,
+                                                 _int8_blocks,
+                                                 _int8_vmem_bytes, bsp_matmul,
+                                                 bsp_matmul_int8, fetch_map)
 from repro.kernels.bsp_matmul.ref import (bsp_matmul_blocked_ref,
                                           bsp_matmul_int8_ref,
                                           bsp_matmul_ref)
@@ -98,18 +102,79 @@ def test_bsp_matmul_skips_tiles(key, interpret):
     np.testing.assert_allclose(np.asarray(out[128:]), 0.0)
 
 
-@pytest.mark.parametrize("mkn", [(128, 128, 128), (256, 128, 384)])
+def _mixed_block_mask(key, M, K, N):
+    """A tile mask whose int8 compute blocks are, in turn, partly live,
+    dead and fully live (a one-block grid gets a partly live block)."""
+    bm, bk, _ = _int8_blocks(M, K, N)
+    tm, tk = bm // 128, bk // 128
+    mb, kb = M // bm, K // bk
+    kind = (np.arange(mb)[:, None] + np.arange(kb)[None, :] + 1) % 3
+    kind = np.repeat(np.repeat(kind, tm, axis=0), tk, axis=1)
+    part = np.asarray(jax.random.bernoulli(key, 0.5, (M // 128, K // 128)))
+    mask = jnp.asarray(np.where(kind == 0, 1, np.where(kind == 1, part, 0)),
+                       jnp.int32)
+    states = set(np.asarray(_block_state(mask, tm, tk)).ravel().tolist())
+    assert _PARTIAL in states and (mb * kb < 3
+                                   or states == {_FULL, _PARTIAL, _DEAD})
+    return mask
+
+
+# (640, 896, 256): one 640 x 896 block of 5 x 7 tiles; (1280, 1792, 384): a
+# 2 x 2 grid of such blocks, partly live, dead, dead and fully live
+MIXED_BLOCK_SHAPES = [(640, 896, 256), (1280, 1792, 384)]
+
+
+@pytest.mark.parametrize("mkn", [(128, 128, 128), (256, 128, 384)]
+                         + MIXED_BLOCK_SHAPES)
 def test_bsp_matmul_int8_vs_ref(key, mkn, interpret):
     M, K, N = mkn
     k_q = jax.random.randint(key, (M, K), -8, 9, jnp.int32).astype(jnp.int8)
     b_q = jax.random.randint(jax.random.fold_in(key, 1), (K, N), -127, 128,
                              jnp.int32).astype(jnp.int8)
     scale = jnp.float32(1.7e-3)
-    mask = jnp.ones((M // 128, K // 128), jnp.int32)
+    if mkn in MIXED_BLOCK_SHAPES:
+        mask = _mixed_block_mask(jax.random.fold_in(key, 2), M, K, N)
+    else:
+        mask = jnp.ones((M // 128, K // 128), jnp.int32)
     out_k = bsp_matmul_int8(k_q, b_q, scale, mask, interpret=interpret)
     out_r = bsp_matmul_int8_ref(k_q, b_q, scale, mask)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
-                               rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+
+
+# mamba2-370m's four int8 calls per layer at 8192 tokens: in-proj dx and
+# dW^T (4384 padded to 4480 = 35 tiles), out-proj dx and dW^T
+MAMBA2_370M_INT8_CALLS = [(8192, 4480, 1024), (4480, 8192, 1024),
+                          (8192, 1024, 2048), (1024, 8192, 2048)]
+
+
+@pytest.mark.parametrize("mkn", MAMBA2_370M_INT8_CALLS,
+                         ids=["in_dx", "in_dw", "out_dx", "out_dw"])
+def test_int8_blocks_at_mamba2_widths(mkn):
+    blocks = _int8_blocks(*mkn)
+    for d, b in zip(mkn, blocks):
+        assert b % 128 == 0 and d % b == 0, (mkn, blocks)
+    assert _int8_vmem_bytes(*blocks, 4) <= _INT8_VMEM_BUDGET
+    M, K, N = mkn
+    bm, bk, bn = blocks
+    assert (M // bm) * (K // bk) * (N // bn) <= 64, blocks
+
+
+@pytest.mark.parametrize("mkn", [(128, 128, 128), (128, 4480, 128),
+                                 (8192, 128, 1024)])
+def test_int8_blocks_128_wide_dims(mkn):
+    """A 128-wide dimension gets a 128 block; the others what they allow."""
+    blocks = _int8_blocks(*mkn)
+    for d, b in zip(mkn, blocks):
+        assert (b == 128) if d == 128 else (b > 128 and d % b == 0)
+
+
+def test_int8_blocks_recorded():
+    M, K, N = 640, 896, 256
+    k_q = jnp.ones((M, K), jnp.int8)
+    b_q = jnp.ones((K, N), jnp.int8)
+    mask = jnp.ones((M // 128, K // 128), jnp.int32)
+    bsp_matmul_int8(k_q, b_q, jnp.float32(1.0), mask, interpret=True)
+    assert INT8_BLOCKS[(M, K, N)] == (640, 896, 256)
 
 
 class TestFullBackward:
@@ -312,14 +377,19 @@ class TestBitExactOracles:
         ref = bsp_matmul_blocked_ref(k_q, delta, b, mask)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
-    def test_int8_kernel_bit_exact_vs_ref(self, key):
-        M, K, N = 256, 256, 128
+    @pytest.mark.parametrize("mkn", [(256, 256, 128)] + MIXED_BLOCK_SHAPES)
+    def test_int8_kernel_bit_exact_vs_ref(self, key, mkn):
+        M, K, N = mkn
         k_q = jax.random.randint(key, (M, K), -127, 128, jnp.int32
                                  ).astype(jnp.int8)
         b_q = jax.random.randint(jax.random.fold_in(key, 1), (K, N), -127,
                                  128, jnp.int32).astype(jnp.int8)
-        mask = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.5,
-                                    (M // 128, K // 128)).astype(jnp.int32)
+        if mkn in MIXED_BLOCK_SHAPES:
+            mask = _mixed_block_mask(jax.random.fold_in(key, 2), M, K, N)
+        else:
+            mask = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.5,
+                                        (M // 128, K // 128)
+                                        ).astype(jnp.int32)
         out = bsp_matmul_int8(k_q, b_q, jnp.float32(1e-3), mask,
                               interpret=True)
         ref = bsp_matmul_int8_ref(k_q, b_q, jnp.float32(1e-3), mask)

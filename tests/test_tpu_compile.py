@@ -53,6 +53,13 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
+def _int8_case(m, k, n):
+    return (lambda a, b, s, mask: bsp_matmul_int8(a, b, s, mask,
+                                                  interpret=False),
+            [((m, k), jnp.int8), ((k, n), jnp.int8), ((), jnp.float32),
+             ((m // BLOCK, k // BLOCK), jnp.int32)])
+
+
 def _cases():
     """name -> (fn, [(shape, dtype), ...]) at the in_proj widths."""
     tiles = (T // BLOCK, NP // BLOCK)
@@ -75,10 +82,12 @@ def _cases():
             lambda k, d, b, m: bsp_matmul(k, d, b, m, interpret=False),
             [((T, NP), jnp.int8), ((), jnp.float32),
              ((NP, D_MODEL), jnp.float32), (tiles, jnp.int32)]),
-        "bsp_matmul_int8": (
-            lambda k, b, s, m: bsp_matmul_int8(k, b, s, m, interpret=False),
-            [((T, NP), jnp.int8), ((NP, D_MODEL), jnp.int8),
-             ((), jnp.float32), (tiles, jnp.int32)]),
+        # the four int8 products of a layer, each at its own blocks:
+        # in_proj dx = g~ @ w^T, in_proj dW^T = g~^T @ x, and out_proj's
+        "bsp_matmul_int8": _int8_case(T, NP, D_MODEL),
+        "bsp_matmul_int8_in_dw": _int8_case(NP, T, D_MODEL),
+        "bsp_matmul_int8_out_dx": _int8_case(T, D_MODEL, D_INNER),
+        "bsp_matmul_int8_out_dw": _int8_case(D_MODEL, T, D_INNER),
         # one wire chunk per column: the in_proj gradient as 256-element
         # chunks, T * NP / 256 columns
         "levels_compact_blocked": (
